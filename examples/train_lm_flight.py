@@ -3,9 +3,9 @@ feeding the training loop), with checkpoint/restart fault tolerance.
 
   PYTHONPATH=src python examples/train_lm_flight.py [--steps 150]
 
-This drives the same ``repro.launch.train`` machinery a TPU pod would use,
-at a CPU-sized reduced config (a ~100M-class run is the same command with
---d-model 768 --layers 12 on real hardware).
+This drives ``repro.launch.train`` on one device at a CPU-sized reduced
+config (a ~100M-class run is the same command with --d-model 768
+--layers 12 on one accelerator chip).
 """
 import argparse
 import sys

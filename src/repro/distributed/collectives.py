@@ -23,21 +23,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .sharding import ShardingCtx
-
-
-def _axis_size(axis_name: str) -> int:
-    """Static mapped-axis size, portable across jax versions.
-
-    ``jax.lax.axis_size`` only exists in newer jax; on 0.4.x the axis frame
-    carries the size (as the frame itself, an int, on 0.4.37)."""
-    if hasattr(jax.lax, "axis_size"):
-        return int(jax.lax.axis_size(axis_name))
-    frame = jax.core.axis_frame(axis_name)
-    return int(getattr(frame, "size", frame))
 
 
 def _quant_chunk(x: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -62,7 +50,7 @@ def compressed_psum_ring(x_local: jax.Array, axis_name: str) -> jax.Array:
     Runs INSIDE shard_map.  x_local: (n,) per-device partial sum, n divisible
     by axis size.  Returns the summed (n,) on every device.
     """
-    n_dev = _axis_size(axis_name)
+    n_dev = int(jax.lax.axis_size(axis_name))
     if n_dev == 1:
         return x_local
     n = x_local.shape[0]
@@ -121,9 +109,9 @@ def compressed_grad_sync(grads, ctx: ShardingCtx, axis: str = "data"):
     flat = jnp.concatenate([l.reshape(-1).astype(jnp.float32) for l in leaves])
     flat = jnp.pad(flat, (0, pad))
     other_axes = [a for a in mesh.axis_names if a != axis]
-    synced = shard_map(
+    synced = jax.shard_map(
         sync_flat, mesh=mesh,
-        in_specs=P(), out_specs=P(), check_rep=False,
+        in_specs=P(), out_specs=P(), check_vma=False,
     )(flat)
     synced = synced[:total]
     out, off = [], 0

@@ -1,6 +1,8 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 # ^ MUST precede any jax import: jax locks the device count on first init.
+# A virtual CPU mesh: pinned to the CPU so it never takes an accelerator.
 # This forcing is dry-run-only — tests/benches see the single real device.
 
 """Multi-pod dry-run: lower + compile every (arch × shape) on the production

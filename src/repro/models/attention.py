@@ -274,13 +274,11 @@ def flash_decode_shardmap(q, k_cache, v_cache, cache_len, ctx, *, softmax_scale=
             o, l = o_loc, l_loc
         return (o / jnp.maximum(l, 1e-30)[..., None])[:, None].astype(q_l.dtype)
 
-    from jax.experimental.shard_map import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         kernel, mesh=mesh,
         in_specs=(qspec, kvspec, kvspec, P()),
         out_specs=outspec,
-        check_rep=False,
+        check_vma=False,
     )
     return fn(q, k_cache, v_cache, jnp.asarray(cache_len, jnp.int32).reshape(-1))
 

@@ -422,13 +422,21 @@ class LM:
         loss = nll + (0.01 * aux if cfg.moe is not None else 0.0)
         return loss, {"nll": nll, "aux": aux}
 
-    def prefill(self, params, batch):
-        """Forward building decode state; returns (next_token_logits, caches)."""
+    def prefill(self, params, batch, lengths=None):
+        """Forward building decode state; returns (next_token_logits, caches).
+
+        ``lengths`` (B,) int32: real tokens per right-padded row; each row's
+        logits come from its own last real position (default: position -1).
+        """
         cfg, ctx = self.cfg, self.ctx
         x, positions = self._embed_inputs(params, batch)
         caches = self._empty_caches_like(x)
         x, _, out_caches = self._run_stack(params, x, positions, "prefill", caches)
-        last = x[:, -1:]
+        if lengths is None:
+            last = x[:, -1:]
+        else:
+            idx = jnp.clip(jnp.asarray(lengths, jnp.int32) - 1, 0, x.shape[1] - 1)
+            last = jnp.take_along_axis(x, idx[:, None, None], axis=1)
         lgts = L.logits(params, last, ctx)[:, 0]
         return lgts, out_caches
 

@@ -25,7 +25,6 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .layers import ParamBuilder, swiglu
@@ -156,12 +155,12 @@ def moe_ffn(params: dict, x: jax.Array, cfg: MoEConfig, ctx) -> tuple[jax.Array,
             y = jax.lax.psum(y, model_ax)
         return y.reshape(b_l, s_l, d)
 
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(in_x, in_x, in_x,
                   P(model_ax, fsdp_ax, None), P(model_ax, fsdp_ax, None), P(model_ax, None, fsdp_ax)),
         out_specs=in_x,
-        check_rep=False,
+        check_vma=False,
     )(x, top_e.astype(jnp.int32), top_w.astype(jnp.float32),
       params["w_gate"], params["w_up"], params["w_down"])
 

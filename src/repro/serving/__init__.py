@@ -1,1 +1,1 @@
-from .service import Batcher, BatcherConfig, LMScoringService, ScoringService  # noqa: F401
+from .service import Batcher, BatcherConfig, LMScoringService, ScoringService, score_tokens  # noqa: F401

@@ -15,19 +15,16 @@ every shape).
 from __future__ import annotations
 
 import threading
-import time
 from dataclasses import dataclass
+from functools import partial
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.array import Array
-from ..core.flight.protocol import FlightDescriptor, FlightError
 from ..core.flight.server import InMemoryFlightServer
 from ..core.recordbatch import RecordBatch
-from ..core.schema import Schema
 
 
 class ScoringService(InMemoryFlightServer):
@@ -90,6 +87,18 @@ class Batcher:
             done.set()
 
 
+@partial(jax.jit, static_argnums=0)
+def score_tokens(model, params, tokens, lengths):
+    """Greedy next token and its logprob for right-padded rows.
+
+    tokens (B, S) int32, lengths (B,) int32 -> (next_token (B,), logprob (B,)).
+    ``params`` is an argument, so the weights never become program constants.
+    """
+    lgts, _ = model.prefill(params, {"tokens": tokens}, lengths=lengths)
+    lp = jax.nn.log_softmax(lgts.astype(jnp.float32), axis=-1)
+    return jnp.argmax(lgts, axis=-1).astype(jnp.int32), jnp.max(lp, axis=-1)
+
+
 class LMScoringService(ScoringService):
     """Scores ``tokens`` list-columns with an LM prefill (greedy next token)."""
 
@@ -97,26 +106,17 @@ class LMScoringService(ScoringService):
         self.model = model
         self.params = params
         self.max_seq = max_seq
-
-        @jax.jit
-        def _score(tokens):
-            lgts, _ = model.prefill(params, {"tokens": tokens})
-            nxt = jnp.argmax(lgts, axis=-1)
-            lp = jax.nn.log_softmax(lgts, axis=-1)
-            return nxt.astype(jnp.int32), jnp.max(lp, axis=-1)
-
-        self._score = _score
         super().__init__(self._score_batch, **kw)
 
     def _score_batch(self, batch: RecordBatch) -> RecordBatch:
-        col = batch.column("tokens")
-        rows = col.to_pylist()
-        B = len(rows)
-        toks = np.zeros((B, self.max_seq), np.int32)
+        rows = batch.column("tokens").to_pylist()
+        toks = np.zeros((len(rows), self.max_seq), np.int32)
+        lens = np.zeros(len(rows), np.int32)
         for i, r in enumerate(rows):
             r = (r or [])[: self.max_seq]
             toks[i, : len(r)] = r
-        nxt, lp = self._score(jnp.asarray(toks))
+            lens[i] = len(r)
+        nxt, lp = score_tokens(self.model, self.params, toks, lens)
         return RecordBatch.from_pydict({
             "next_token": np.asarray(nxt),
             "logprob": np.asarray(lp, np.float32),

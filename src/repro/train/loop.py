@@ -17,15 +17,15 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..data.loader import FlightDataLoader, LoaderState
 from ..distributed.checkpoint import CheckpointManager
 from ..distributed.collectives import compressed_psum_ring, quantized_error_feedback
 from ..distributed.fault import FailureDetector, StragglerDetector
+from ..distributed.sharding import tree_shardings
 from ..models.lm import LM
-from .optimizer import OptimizerConfig, make_optimizer
+from .optimizer import OptimizerConfig, make_optimizer, opt_state_axes_with_params
 from .step import TrainConfig, build_train_step
 
 
@@ -53,8 +53,14 @@ class Trainer:
         self._opt_init = opt_init
 
     def init_state(self, seed: int = 0):
-        params, _ = self.model.init(jax.random.key(seed))
-        opt_state = self._opt_init(params)
+        """Fresh params and optimizer state, placed with the mesh shardings the
+        step returns them with, so the step compiles once and not again at step 2."""
+        params, axes = self.model.init(jax.random.key(seed))
+        ctx = self.model.ctx
+        opt_axes = opt_state_axes_with_params(self.cfg.train.optimizer, params, axes)
+        params = jax.device_put(params, tree_shardings(axes, ctx.mesh, ctx.rules))
+        opt_state = jax.device_put(self._opt_init(params),
+                                   tree_shardings(opt_axes, ctx.mesh, ctx.rules))
         return {"params": params, "opt": opt_state, "step": 0}
 
     def restore_or_init(self, seed: int = 0):
@@ -144,11 +150,11 @@ def build_dp_train_step(model: LM, opt_cfg: OptimizerConfig, mesh, axis: str = "
 
         other = [a for a in mesh.axis_names if a != axis]
         rep = P(*([None]))
-        loss, grads, new_res = shard_map(
+        loss, grads, new_res = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), P(axis), P()),
             out_specs=(P(), P(), P()),
-            check_rep=False,
+            check_vma=False,
         )(params, batch, residual)
         new_params, new_opt, metrics = opt_update(grads, opt_state, params)
         return new_params, new_opt, new_res, {"loss": loss, **metrics}

@@ -6,19 +6,9 @@ import pytest
 
 HERE = Path(__file__).resolve().parent
 SRC = HERE.parent / "src"
-for p in (str(SRC), str(HERE)):
+for p in (str(SRC), str(HERE), str(HERE.parent)):
     if p not in sys.path:
         sys.path.insert(0, p)
-
-# hypothesis is a dev-only dependency (requirements-dev.txt, installed in CI).
-# Offline containers fall back to a deterministic in-tree stub so the suite
-# still collects and the property tests run with random examples.
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    import _hypothesis_stub
-
-    _hypothesis_stub.install()
 
 
 def pytest_addoption(parser):

@@ -83,6 +83,30 @@ class TestScoring:
         finally:
             svc.shutdown()
 
+    def test_ragged_batch_matches_unpadded_prefill(self):
+        """Each row is answered from its own last real token: a ragged batch
+        served over TCP equals a prefill of each prompt alone, unpadded."""
+        cfg = get_smoke_config("internlm2_1_8b")
+        model = LM(cfg, single_device_ctx())
+        params, _ = model.init(jax.random.key(0))
+        rng = np.random.default_rng(0)
+        rows = [rng.integers(1, cfg.vocab, n).tolist() for n in (3, 17, 32, 9, 1)]
+        svc = LMScoringService(model, params, max_seq=32).serve_tcp()
+        try:
+            c = FlightClient(f"tcp://127.0.0.1:{svc.port}")
+            req = RecordBatch.from_pydict({"tokens": rows})
+            ex = c.do_exchange_stream(FlightDescriptor.for_path("score"), req.schema)
+            ex.feed([req])
+            (out,) = list(ex)
+            ex.close()
+        finally:
+            svc.shutdown()
+        for i, r in enumerate(rows):
+            lg, _ = model.prefill(params, {"tokens": np.asarray([r], np.int32)})
+            lp = jax.nn.log_softmax(np.asarray(lg[0], np.float32))
+            assert out.column("next_token").to_pylist()[i] == int(np.argmax(lp))
+            assert abs(out.column("logprob").to_pylist()[i] - float(np.max(lp))) <= 1e-5
+
     def test_batcher_coalesces(self):
         calls = []
 

@@ -164,7 +164,6 @@ def test_compressed_ring_allreduce_multidevice():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.distributed.collectives import compressed_psum_ring
         mesh = Mesh(np.array(jax.devices()).reshape(8), ("data",))
@@ -174,8 +173,8 @@ def test_compressed_ring_allreduce_multidevice():
         def exact(xl):
             return jax.lax.psum(xl.reshape(-1), "data")
         with mesh:
-            r = shard_map(ring, mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_rep=False)(x)
-            e = shard_map(exact, mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_rep=False)(x)
+            r = jax.shard_map(ring, mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_vma=False)(x)
+            e = jax.shard_map(exact, mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_vma=False)(x)
         r, e = np.asarray(r), np.asarray(e)
         rel = np.abs(r - e).max() / (np.abs(e).max() + 1e-9)
         assert rel < 0.02, rel

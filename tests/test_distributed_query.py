@@ -6,7 +6,7 @@ equi-joins, replica death mid-query) must be element-equal to the
 single-node ``query.engine`` oracle run over the same rows.  Structure
 (row count, group cardinality, key dtype, shard count, placement) is drawn
 by hypothesis; bulk values come from a numpy generator seeded by a drawn
-seed, so the suite runs identically under ``tests/_hypothesis_stub.py``.
+seed.
 
 Equality contract: group keys, counts, integer sums and extrema compare
 exactly; float sums/means compare within 1e-9 relative (distributed merge

@@ -10,7 +10,8 @@ from repro.models.lm import LM
 from repro.models import layers as L
 from repro.models.attention import HeadLayout, flash_attention
 from repro.models.mamba import MambaConfig, init_mamba, mamba_init_state, mamba_mix
-from repro.models.xlstm import XLSTMConfig, init_mlstm, mlstm_init_state, mlstm_mix
+from repro.models.xlstm import (XLSTMConfig, init_mlstm, init_slstm, mlstm_init_state,
+                                mlstm_mix, slstm_mix)
 
 
 def build(arch):
@@ -119,6 +120,23 @@ def test_mlstm_chunked_equals_sequential():
     y_seq = jnp.concatenate(outs, axis=1)
     np.testing.assert_allclose(np.asarray(y_par, np.float32),
                                np.asarray(y_seq, np.float32), atol=5e-2)
+
+
+def test_slstm_gradients_finite_over_long_sequence():
+    """At xlstm_350m width (4 heads of 256) the recurrent weights' init must
+    not make gradients explode through 1024 time steps of the sLSTM scan."""
+    cfg = XLSTMConfig(d_model=1024, n_heads=4)
+    pb = L.ParamBuilder(jax.random.key(0))
+    init_slstm(pb, cfg)
+    ctx = single_device_ctx()
+    x = jax.random.normal(jax.random.key(1), (1, 1024, 1024), jnp.float32).astype(jnp.bfloat16)
+
+    def loss(p, x):
+        out, _ = slstm_mix(p, x, ctx)
+        return jnp.mean(jnp.square(out.astype(jnp.float32)))
+
+    grads = jax.jit(jax.grad(loss, argnums=(0, 1)))(pb.params, x)
+    assert all(np.all(np.isfinite(np.asarray(g, np.float32))) for g in jax.tree.leaves(grads))
 
 
 def test_param_count_matches_actual():
