@@ -583,6 +583,7 @@ class EventLoopListener:
         except Exception:
             return False  # a broken predicate degrades to the worker path
         ch.bytes_received += FRAME.size + len(meta_raw)
+        ch.last_queue_wait_s = 0.0  # the opening frame never sat in the inbox
         self.inline_rpcs += 1
         t0 = time.perf_counter() if self._telemetry else 0.0
         try:

@@ -125,10 +125,11 @@ class MetricsMiddleware(ServerMiddleware):
     When constructed with a ``ServerTelemetry`` in ``"full"`` mode this is
     also the server-side tracer: a request arriving with trace headers gets
     a child ``Span`` opened in ``on_call`` (installed as the thread-local
-    active span so handlers can ``add_stage``) and recorded in
-    ``on_complete`` with queue-wait and handler stage timings.  Untraced
-    requests pay one header lookup.  Everything here is non-blocking and
-    allocation-light on purpose: this middleware lives in the
+    active span so handlers can ``add_stage`` and open child spans) and
+    recorded in ``on_complete`` with queue-wait and handler stage timings;
+    under sampling ``"all"`` every other request gets a root span.
+    Untraced requests pay one header lookup.  Everything here is
+    non-blocking and allocation-light on purpose: this middleware lives in the
     ``MiddlewareStack`` module, so it must keep the event loop's inline
     fast-path certificate valid (see ``FlightServerBase._rpc_inline_ok``).
 
@@ -167,15 +168,17 @@ class MetricsMiddleware(ServerMiddleware):
         tel = self.telemetry
         if tel is not None and tel.trace_enabled:
             parent = TraceContext.from_headers(ctx.headers)
-            if parent is not None:  # caller-sampled: only traced requests pay
+            # caller-sampled: only traced requests pay; sampling "all" gives
+            # an untraced request a root span of its own
+            if parent is not None or tel.sample == "all":
                 name = ctx.method
                 if name == "DoAction":
                     name = f"DoAction:{(ctx.request.get('action') or {}).get('type', '?')}"
                 elif name == "DoExchange":
                     name = f"DoExchange:{ctx.state.get('metrics_exchange', '?')}"
-                span, prev = tel.begin_span(name, parent)
+                span, prev = tel.begin_span(name, parent, mono_s=ctx.state["metrics_t0"])
                 qw = ctx.state.get("queue_wait_s")
-                if qw:
+                if qw is not None:
                     span.stages["queue"] = qw
                 ctx.state["telemetry_span"] = (span, prev)
 

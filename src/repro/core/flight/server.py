@@ -435,10 +435,11 @@ class FlightServerBase:
         opts = req.get("options") or {}
         ctx = self._call_context(method or "?", req)
         # event-loop channels stamp how long the opening frame sat parsed in
-        # the inbox before a worker picked it up; traced spans surface it as
-        # the "queue" stage (inline dispatch never queues — no attribute)
-        queue_wait = getattr(conn, "last_queue_wait_s", 0.0)
-        if queue_wait:
+        # the inbox before a worker picked it up (0 inline: it never
+        # queued); traced spans surface it as the "queue" stage.  Threaded
+        # connections have no inbox, and no stage
+        queue_wait = getattr(conn, "last_queue_wait_s", None)
+        if queue_wait is not None:
             ctx.state["queue_wait_s"] = queue_wait
         try:
             # unary verbs buffer their reply and send it *after* the
@@ -624,7 +625,30 @@ class FlightServerBase:
         control frames riding the output direction), and the client writer
         blocks once ``window`` batches are unacked — so at most ``window``
         batches are ever queued in the socket, and a serial server never
-        needs its own input queue."""
+        needs its own input queue.
+
+        A traced call leaves two child spans of its RPC span: ``flight.read``
+        from here until its first input batch is decoded (or its input
+        ends), and ``flight.reply`` from its first output batch (or, with
+        none, its end of stream) to the final ``ok``.  In a streaming call
+        the later batches' work runs inside ``flight.reply``.  A call that
+        fails records its RPC span's error and neither interval."""
+        tel = self.telemetry
+        read = tel.interval("flight.read")  # None when untraced
+        traced = read is not None
+        reply = None
+
+        def end_read() -> None:
+            nonlocal read
+            if read is not None:
+                tel.close(read)
+                read = None
+
+        def begin_reply() -> None:
+            nonlocal reply
+            if traced and reply is None:
+                reply = tel.interval("flight.reply")
+
         kind, meta, body = conn.recv_frame()
         if kind != KIND_DATA:
             raise FlightInvalidArgument("exchange: expected a schema data frame first")
@@ -657,6 +681,10 @@ class FlightServerBase:
             if not coalesce or pending_bytes >= COALESCE_BYTES:
                 flush()
 
+        def emit_batch(ob: RecordBatch) -> None:
+            begin_reply()
+            emit(encode_batch(ob, codec))
+
         def inputs() -> Iterator[RecordBatch]:
             while True:
                 if not conn.receive_ready():
@@ -670,6 +698,7 @@ class FlightServerBase:
                     if state["acked"] != state["in"]:  # final ack frees the writer
                         conn.send_ctrl({"ack": state["in"]})
                         state["acked"] = state["in"]
+                    end_read()
                     return
                 if dm.kind == "schema":
                     raise FlightInvalidArgument("exchange: duplicate schema mid-stream")
@@ -678,7 +707,9 @@ class FlightServerBase:
                 if state["in"] - state["acked"] >= every:
                     conn.send_ctrl({"ack": state["in"]})
                     state["acked"] = state["in"]
-                yield dm.batch(in_schema)
+                batch = dm.batch(in_schema)
+                end_read()
+                yield batch
 
         # `declare` sends directly: it only ever runs with nothing pending
         # (up front, or immediately before the first output batch), so the
@@ -686,9 +717,10 @@ class FlightServerBase:
         drive_exchange(
             service, in_schema, params, inputs(),
             declare=lambda s: conn.send_data(encode_schema(s)),
-            emit=lambda ob: emit(encode_batch(ob, codec)),
+            emit=emit_batch,
             state=state,
         )
+        begin_reply()
         emit(encode_eos(codec))
         flush()
         conn.send_ctrl({"ok": True, "stats": {
@@ -696,6 +728,8 @@ class FlightServerBase:
             "batches_in": state["in"], "rows_in": state["rows_in"],
             "batches_out": state["out"], "rows_out": state["rows_out"],
         }})
+        if reply is not None:
+            tel.close(reply)
 
 
 def _query_out_schema(plan, schema: Schema) -> Schema:

@@ -12,12 +12,17 @@ cluster.py.
 rides ``CallOptions.headers`` (client → server) and endpoint
 ``app_metadata["trace"]`` (planner → scheduler → shard), so one trace
 stitches every hop of a distributed read, a 2PC commit, or a chained
-exchange pipeline.  Tracing is **sampled by the caller**: servers only
-record spans for requests that arrive carrying trace headers — untraced
-traffic pays one dict lookup per RPC and nothing else.  Each recorded
-``Span`` carries per-stage timings (queue-wait, handler, encode, flush,
-backpressure stalls) filled in by the server and event loop via the
-thread-local ``add_stage`` hook.
+exchange pipeline.  Tracing is **sampled by the caller** by default:
+servers only record spans for requests that arrive carrying trace headers —
+untraced traffic pays one dict lookup per RPC and nothing else.  An operator
+can switch a server to **server-rooted** sampling at run time
+(``server-trace`` with ``{"sample": "all"}``): every RPC then opens a root
+span of its own.  Each recorded ``Span`` carries per-stage timings
+(queue-wait, handler, encode, flush, backpressure stalls) filled in by the
+server and event loop via the thread-local ``add_stage`` hook, and its start
+on two clocks: ``start_s`` (wall, for stitching hosts) and ``mono_s``
+(``time.perf_counter``, for placing child intervals and lining spans up
+with other monotonic timelines of the same machine).
 
 **Latency histograms.**  ``LogHistogram`` is a fixed-size log2-bucket
 histogram (one integer increment per observation, no allocation, no lock —
@@ -43,18 +48,22 @@ import os
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
 
 from ..recordbatch import RecordBatch
 from ..ipc import read_stream_with_schema, write_stream
+from .errors import FlightInvalidArgument
 
 # Trace headers (CallOptions.headers / endpoint app_metadata["trace"] keys).
 HDR_TRACE = "x-trace-id"
 HDR_SPAN = "x-span-id"
 HDR_PARENT = "x-parent-span"
 
-MAX_SPANS = 2048      # bounded per-server span buffer (drop-oldest)
+# bounded per-server span buffer (drop-oldest): a scoring request leaves 7
+# spans, so this holds ~2300 requests — a 45-s window at 13 req/s 3x over
+MAX_SPANS = 16384
+SAMPLING = ("caller", "all")
 MAX_BUCKETS = 40      # log2 µs buckets: 2**39 µs ≈ 9.1 min ceiling
 
 
@@ -109,25 +118,32 @@ class Span:
     name: str
     service: str = "?"
     shard: int = -1
-    start_s: float = 0.0
+    start_s: float = 0.0      # wall clock (time.time), comparable across hosts
     duration_s: float = 0.0
     status: str = "ok"        # "ok" or the FlightError wire code
     stages: dict = field(default_factory=dict)  # stage name -> seconds
+    mono_s: float = 0.0       # start on time.perf_counter, this host's monotonic clock
 
     def context(self) -> TraceContext:
         return TraceContext(self.trace_id, self.span_id, self.parent_id)
 
 
 class SpanRecorder:
-    """Bounded, thread-safe span sink (drop-oldest ring)."""
+    """Bounded, thread-safe span sink (drop-oldest ring).
+
+    ``recorded`` counts every span ever recorded, ``dropped`` those pushed
+    out of a full ring before any snapshot drained them."""
 
     def __init__(self, maxlen: int = MAX_SPANS):
         self._lock = threading.Lock()
         self._spans: deque[Span] = deque(maxlen=maxlen)
         self.recorded = 0
+        self.dropped = 0
 
     def record(self, span: Span) -> None:
         with self._lock:
+            if len(self._spans) == self._spans.maxlen:
+                self.dropped += 1
             self._spans.append(span)
             self.recorded += 1
 
@@ -258,19 +274,29 @@ def _pop_span(prev: Span | None) -> None:
 
 
 class ServerTelemetry:
-    """What one server owns: mode, identity, and the span sink.
+    """What one server owns: mode, sampling, identity, and the span sink.
 
     ``mode`` gates cost: ``"off"`` (no histograms, no spans), ``"metrics"``
-    (histograms only), ``"full"`` (histograms + caller-sampled spans)."""
+    (histograms only), ``"full"`` (histograms + sampled spans).  Under
+    ``"full"``, ``sample`` picks which RPCs are traced: ``"caller"`` (the
+    default) those that arrive with trace headers, ``"all"`` every RPC, the
+    untraced ones under a root span of their own; ``set_sample`` switches it
+    at run time."""
 
     def __init__(self, mode: str = "full", service: str = "?",
                  shard: int | None = None):
         if mode not in ("off", "metrics", "full"):
             raise ValueError(f"telemetry mode {mode!r} (off|metrics|full)")
         self.mode = mode
+        self.sample = "caller"
         self.service = service
         self.shard = -1 if shard is None else shard
         self.spans = SpanRecorder()
+
+    def set_sample(self, sample: str) -> None:
+        if sample not in SAMPLING:
+            raise ValueError(f"trace sampling {sample!r} (caller|all)")
+        self.sample = sample
 
     @property
     def metrics_enabled(self) -> bool:
@@ -280,46 +306,80 @@ class ServerTelemetry:
     def trace_enabled(self) -> bool:
         return self.mode == "full"
 
-    def begin_span(self, name: str, parent: TraceContext) -> tuple[Span, Span | None]:
-        """Open a server span as a child of the caller's context and make
-        it the thread's active span; returns ``(span, previous)`` for the
-        matching ``end_span``."""
-        span = Span(
-            trace_id=parent.trace_id, span_id=_new_id(),
-            parent_id=parent.span_id, name=name,
-            service=self.service, shard=self.shard, start_s=time.time())
+    def _new_span(self, name: str, trace_id: str, parent_id: str | None,
+                  mono_s: float | None = None) -> Span:
+        return Span(
+            trace_id=trace_id, span_id=_new_id(), parent_id=parent_id,
+            name=name, service=self.service, shard=self.shard,
+            start_s=time.time(),
+            mono_s=time.perf_counter() if mono_s is None else mono_s)
+
+    def begin_span(self, name: str, parent: TraceContext | None,
+                   mono_s: float | None = None) -> tuple[Span, Span | None]:
+        """Open a server span as a child of the caller's context (a root
+        span of a fresh trace when ``parent`` is None) and make it the
+        thread's active span; returns ``(span, previous)`` for the matching
+        ``end_span``.  ``mono_s``: its start, when it began before this
+        call (default: now)."""
+        if parent is None:
+            span = self._new_span(name, _new_id(), None, mono_s)
+        else:
+            span = self._new_span(name, parent.trace_id, parent.span_id, mono_s)
         return span, _push_span(span)
 
     def end_span(self, span: Span, prev: Span | None, duration_s: float,
                  error: Exception | None = None) -> None:
+        _pop_span(prev)
+        self._finish(span, duration_s, error)
+        span.stages.setdefault("handler", duration_s)
+        self.spans.record(span)
+
+    def _finish(self, span: Span, duration_s: float,
+                error: Exception | None) -> None:
         span.duration_s = duration_s
         if error is not None:
             span.status = getattr(error, "code", None) or type(error).__name__
-        span.stages.setdefault("handler", duration_s)
-        _pop_span(prev)
+
+    def interval(self, name: str) -> Span | None:
+        """Open a child of the thread's active span without making it the
+        active span, for an interval that ends in another frame than it
+        began (``close`` records it); ``None`` when untraced."""
+        parent = getattr(_tls, "span", None)
+        if parent is None or not self.trace_enabled:
+            return None
+        return self._new_span(name, parent.trace_id, parent.span_id)
+
+    def close(self, span: Span, error: Exception | None = None) -> None:
+        """Record an ``interval`` span, ending now."""
+        self._finish(span, time.perf_counter() - span.mono_s, error)
         self.spans.record(span)
 
-    @contextmanager
     def span(self, name: str, parent: TraceContext | None = None):
         """Record an explicit sub-span (e.g. a 2PC sub-txn run in-proc,
         bypassing middleware).  Parent defaults to the thread's active
-        span; with no parent and no active trace this is a no-op."""
+        span; with no parent and no active trace this is a no-op that
+        allocates nothing."""
+        if parent is None and (getattr(_tls, "span", None) is None
+                               or not self.trace_enabled):
+            return _NO_SPAN
+        return self._span(name, parent)
+
+    @contextmanager
+    def _span(self, name: str, parent: TraceContext | None):
         if not self.trace_enabled:
             yield None
             return
-        parent = parent or current_context()
-        if parent is None:
-            yield None
-            return
-        span, prev = self.begin_span(name, parent)
-        t0 = time.perf_counter()
+        span, prev = self.begin_span(name, parent or current_context())
         try:
             yield span
         except Exception as e:
-            self.end_span(span, prev, time.perf_counter() - t0, e)
+            self.end_span(span, prev, time.perf_counter() - span.mono_s, e)
             raise
         else:
-            self.end_span(span, prev, time.perf_counter() - t0)
+            self.end_span(span, prev, time.perf_counter() - span.mono_s)
+
+
+_NO_SPAN = nullcontext()  # stateless: one instance serves every untraced span()
 
 
 class Tracer:
@@ -339,16 +399,15 @@ class Tracer:
         ctx = TraceContext.new()
         span = Span(trace_id=ctx.trace_id, span_id=ctx.span_id,
                     parent_id=None, name=name, service=self.service,
-                    start_s=time.time())
+                    start_s=time.time(), mono_s=time.perf_counter())
         prev = _push_span(span)
-        t0 = time.perf_counter()
         try:
             yield ctx
         except Exception as e:
             span.status = getattr(e, "code", None) or type(e).__name__
             raise
         finally:
-            span.duration_s = time.perf_counter() - t0
+            span.duration_s = time.perf_counter() - span.mono_s
             _pop_span(prev)
             self.spans.record(span)
 
@@ -372,13 +431,14 @@ def spans_to_batch(spans: list[Span]) -> RecordBatch:
         "status": [s.status for s in spans],
         "stages": [json.dumps({k: round(v, 9) for k, v in s.stages.items()})
                    for s in spans],
+        "mono_s": [float(s.mono_s) for s in spans],
     } if spans else _EMPTY_SPANS)
 
 
 _EMPTY_SPANS = {
     "trace_id": [], "span_id": [], "parent_id": [], "name": [],
     "service": [], "shard": [], "start_s": [], "duration_s": [],
-    "status": [], "stages": [],
+    "status": [], "stages": [], "mono_s": [],
 }
 
 
@@ -494,12 +554,15 @@ def server_metrics_rows(server) -> list[dict]:
     listener = getattr(server, "_listener", None)
     if listener is not None:
         rows += metrics_rows("io", getattr(listener, "histograms", lambda: {})())
-    # monotone serve counters (no histogram): scrape deltas give rates
-    rows.append({
-        "scope": "serve", "name": "rows_served",
-        "count": int(getattr(server, "rows_served", 0)),
-        "sum_s": 0.0, "p50_s": 0.0, "p95_s": 0.0, "p99_s": 0.0,
-        "buckets": "{}"})
+    # monotone serve counters (no histogram): scrape deltas give rates;
+    # a server adds its own through ``serve_counters()``
+    counters = {"rows_served": int(getattr(server, "rows_served", 0))}
+    counters.update(getattr(server, "serve_counters", dict)())
+    for name, n in counters.items():
+        rows.append({
+            "scope": "serve", "name": name, "count": int(n),
+            "sum_s": 0.0, "p50_s": 0.0, "p95_s": 0.0, "p99_s": 0.0,
+            "buckets": "{}"})
     tel = getattr(server, "telemetry", None)
     shard = tel.shard if tel is not None else -1
     for r in rows:
@@ -509,7 +572,14 @@ def server_metrics_rows(server) -> list[dict]:
 
 def telemetry_action(server, action) -> "list | None":
     """Serve ``server-trace`` / ``server-metrics`` for one server; returns
-    ``None`` for any other action type (caller falls through)."""
+    ``None`` for any other action type (caller falls through).
+
+    ``server-trace`` takes an optional JSON body: ``"sample"`` (``"caller"``
+    or ``"all"``) switches the server's sampling before the snapshot,
+    ``"clear"`` drains the span ring after it.  Its reply is the span batch,
+    then a JSON result ``{"sample", "recorded", "dropped", "returned"}``:
+    the ring's totals since the server started (a window's drops are the
+    difference of two scrapes)."""
     from .protocol import ActionResult  # lazy: protocol imports stay light
 
     if action.type == "server-metrics":
@@ -518,6 +588,16 @@ def telemetry_action(server, action) -> "list | None":
     if action.type == "server-trace":
         opts = json.loads(action.body) if action.body else {}
         tel = getattr(server, "telemetry", None)
-        spans = tel.spans.snapshot(clear=bool(opts.get("clear"))) if tel else []
-        return [ActionResult(encode_telemetry_batch(spans_to_batch(spans)))]
+        if tel is None:
+            return [ActionResult(encode_telemetry_batch(spans_to_batch([])))]
+        if "sample" in opts:
+            try:
+                tel.set_sample(opts["sample"])
+            except ValueError as e:
+                raise FlightInvalidArgument(str(e)) from None
+        spans = tel.spans.snapshot(clear=bool(opts.get("clear")))
+        ring = {"sample": tel.sample, "recorded": tel.spans.recorded,
+                "dropped": tel.spans.dropped, "returned": len(spans)}
+        return [ActionResult(encode_telemetry_batch(spans_to_batch(spans))),
+                ActionResult(json.dumps(ring).encode())]
     return None

@@ -28,17 +28,26 @@ from ..core.recordbatch import RecordBatch
 
 
 class ScoringService(InMemoryFlightServer):
-    """DoExchange(batch) -> score_fn(batch).  score_fn: RecordBatch -> RecordBatch."""
+    """DoExchange(batch) -> score_fn(batch).  score_fn: RecordBatch -> RecordBatch.
+
+    ``requests`` counts the batches scored; ``server-metrics`` exports it
+    in the ``serve`` scope (``serve_counters``), a monotone count whose
+    scrape deltas give rates."""
 
     def __init__(self, score_fn: Callable[[RecordBatch], RecordBatch], **kw):
         super().__init__(**kw)
         self.score_fn = score_fn
-        self.requests_served = 0
+        self.requests = 0
+        self._count_lock = threading.Lock()  # handlers run on many workers
 
     def do_exchange_impl(self, descriptor, schema, batch) -> RecordBatch:
         out = self.score_fn(batch)
-        self.requests_served += 1
+        with self._count_lock:
+            self.requests += 1
         return out
+
+    def serve_counters(self) -> dict:
+        return {"requests": self.requests}
 
 
 @dataclass
@@ -100,24 +109,45 @@ def score_tokens(model, params, tokens, lengths):
 
 
 class LMScoringService(ScoringService):
-    """Scores ``tokens`` list-columns with an LM prefill (greedy next token)."""
+    """Scores ``tokens`` list-columns with an LM prefill (greedy next token).
+
+    Every batch is padded to ``(rows, max_seq)``: ``tokens_real`` counts the
+    prompt tokens scored, ``tokens_padded`` the slots they were padded into
+    (both exported beside ``requests``), so their ratio is the padding's
+    fill.  A traced request leaves four child spans of its RPC span:
+    ``serve.decode`` (rows to the padded array), ``serve.dispatch`` (the
+    jitted call returning; the device runs on asynchronously),
+    ``serve.sync`` (waiting for the outputs, behind any other worker's
+    programs too) and ``serve.reply`` (the reply batch)."""
 
     def __init__(self, model, params, max_seq: int = 512, **kw):
         self.model = model
         self.params = params
         self.max_seq = max_seq
+        self.tokens_real = 0
+        self.tokens_padded = 0
         super().__init__(self._score_batch, **kw)
 
+    def serve_counters(self) -> dict:
+        return {**super().serve_counters(), "tokens_real": self.tokens_real,
+                "tokens_padded": self.tokens_padded}
+
     def _score_batch(self, batch: RecordBatch) -> RecordBatch:
-        rows = batch.column("tokens").to_pylist()
-        toks = np.zeros((len(rows), self.max_seq), np.int32)
-        lens = np.zeros(len(rows), np.int32)
-        for i, r in enumerate(rows):
-            r = (r or [])[: self.max_seq]
-            toks[i, : len(r)] = r
-            lens[i] = len(r)
-        nxt, lp = score_tokens(self.model, self.params, toks, lens)
-        return RecordBatch.from_pydict({
-            "next_token": np.asarray(nxt),
-            "logprob": np.asarray(lp, np.float32),
-        })
+        span = self.telemetry.span
+        with span("serve.decode"):
+            rows = batch.column("tokens").to_pylist()
+            toks = np.zeros((len(rows), self.max_seq), np.int32)
+            lens = np.zeros(len(rows), np.int32)
+            for i, r in enumerate(rows):
+                r = (r or [])[: self.max_seq]
+                toks[i, : len(r)] = r
+                lens[i] = len(r)
+        with self._count_lock:
+            self.tokens_real += int(lens.sum())
+            self.tokens_padded += toks.size
+        with span("serve.dispatch"):
+            nxt, lp = score_tokens(self.model, self.params, toks, lens)
+        with span("serve.sync"):
+            nxt, lp = np.asarray(nxt), np.asarray(lp, np.float32)
+        with span("serve.reply"):
+            return RecordBatch.from_pydict({"next_token": nxt, "logprob": lp})

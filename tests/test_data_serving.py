@@ -107,6 +107,33 @@ class TestScoring:
             assert out.column("next_token").to_pylist()[i] == int(np.argmax(lp))
             assert abs(out.column("logprob").to_pylist()[i] - float(np.max(lp))) <= 1e-5
 
+    def test_request_counter_survives_concurrent_workers(self):
+        """Handlers of many connections bump the serve counters at once."""
+        import sys
+
+        from repro.serving import ScoringService
+
+        svc = ScoringService(lambda b: b)
+        batch = RecordBatch.from_pydict({"x": [1]})
+        desc = FlightDescriptor.for_path("score")
+
+        def worker():
+            for _ in range(500):
+                svc.do_exchange_impl(desc, batch.schema, batch)
+
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            threads = [threading.Thread(target=worker) for _ in range(16)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+        finally:
+            sys.setswitchinterval(interval)
+        assert not any(t.is_alive() for t in threads)
+        assert svc.serve_counters() == {"requests": 16 * 500}
+
     def test_batcher_coalesces(self):
         calls = []
 

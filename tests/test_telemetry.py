@@ -5,6 +5,7 @@ replicated-cluster query must stitch client + head + shard spans under a
 single trace id, each with non-zero stage timings.
 """
 import json
+import time
 
 import pytest
 
@@ -14,6 +15,7 @@ from repro.core.flight import (
     FlightClient,
     FlightClusterClient,
     FlightClusterServer,
+    FlightInvalidArgument,
     FlightNotFound,
     InMemoryFlightServer,
     LogHistogram,
@@ -32,6 +34,7 @@ from repro.core.flight.telemetry import (
     MAX_BUCKETS,
     ServerTelemetry,
     Span,
+    SpanRecorder,
     encode_telemetry_batch,
     merge_telemetry_batches,
     metrics_rows,
@@ -417,3 +420,168 @@ class TestClusterTraceTCP:
             assert all(s["status"] == "ok" for s in txn)
         finally:
             cl.shutdown()
+
+
+# --------------------------------------------------------------------------
+# server-rooted sampling, interval spans of a scoring request, ring drops
+# --------------------------------------------------------------------------
+
+SCORE_CHILDREN = ["flight.read", "serve.decode", "serve.dispatch", "serve.sync",
+                  "serve.reply", "flight.reply"]  # in the order they run
+
+
+def score_once(client, rows):
+    """One DoExchange as the benchmark's load generator sends it: schema,
+    batch and end of stream back to back."""
+    req = RecordBatch.from_pydict({"tokens": rows})
+    ex = client.do_exchange_stream(FlightDescriptor.for_path("score"), req.schema)
+    ex.write_batch(req)
+    ex.done_writing()
+    out = list(ex)
+    ex.close()
+    return out
+
+
+def scrape_trace(client, **opts):
+    """(span rows, ring totals) from ``server-trace``."""
+    res = client.do_action(Action("server-trace", json.dumps(opts).encode()))
+    return batch_to_spans(decode_telemetry_batch(res[0].body)), json.loads(res[1].body)
+
+
+@pytest.fixture(scope="module")
+def scorer():
+    import jax
+
+    from repro.configs import get_smoke_config
+    from repro.distributed.sharding import single_device_ctx
+    from repro.models.lm import LM
+    from repro.serving import LMScoringService
+
+    model = LM(get_smoke_config("internlm2_1_8b"), single_device_ctx())
+    params, _ = model.init(jax.random.key(0))
+    svc = LMScoringService(model, params, max_seq=32).serve_tcp()
+    client = FlightClient(f"tcp://127.0.0.1:{svc.port}")
+    score_once(client, [[1, 2, 3]])  # compiles the one program shape
+    yield svc, client
+    svc.shutdown()
+
+
+def traced_score(client, sample, caller):
+    """Score one prompt under ``sample``, with trace headers if ``caller``;
+    returns the spans of that call and the caller's context (or None)."""
+    scrape_trace(client, sample=sample, clear=True)
+    ctx = None
+    if caller:
+        tracer = Tracer()
+        with tracer.trace("score") as ctx:
+            score_once(client, [[5, 6, 7, 8]])
+    else:
+        score_once(client, [[5, 6, 7, 8]])
+    spans, _ = scrape_trace(client, sample="caller", clear=True)
+    return [s for s in spans if not s["name"].startswith("DoAction")], ctx
+
+
+class TestServerRootedSampling:
+    @pytest.mark.parametrize("sample", ["caller", "all"])
+    def test_header_less_exchange_is_traced_only_under_all(self, scorer, sample):
+        _, client = scorer
+        spans, _ = traced_score(client, sample, caller=False)
+        if sample == "caller":
+            assert spans == []
+            return
+        [rpc] = [s for s in spans if s["name"] == "DoExchange:path:score"]
+        assert rpc["parent_id"] == ""  # a root of its own trace
+        children = sorted((s for s in spans if s is not rpc), key=lambda s: s["mono_s"])
+        assert [s["name"] for s in children] == SCORE_CHILDREN
+        assert {s["parent_id"] for s in children} == {rpc["span_id"]}
+        assert {s["trace_id"] for s in spans} == {rpc["trace_id"]}
+        assert rpc["stages"]["queue"] >= 0  # the opening frame's inbox dwell
+
+    @pytest.mark.parametrize("sample", ["caller", "all"])
+    def test_caller_traced_exchange_keeps_the_callers_trace(self, scorer, sample):
+        _, client = scorer
+        spans, ctx = traced_score(client, sample, caller=True)
+        [rpc] = [s for s in spans if s["name"] == "DoExchange:path:score"]
+        assert rpc["trace_id"] == ctx.trace_id and rpc["parent_id"] == ctx.span_id
+        assert sorted(s["name"] for s in spans if s is not rpc) == sorted(SCORE_CHILDREN)
+
+    @pytest.mark.parametrize("sample", ["caller", "all"])
+    def test_children_nest_in_order_inside_the_rpc_span(self, scorer, sample):
+        _, client = scorer
+        spans, _ = traced_score(client, sample, caller=True)
+        [rpc] = [s for s in spans if s["name"] == "DoExchange:path:score"]
+        children = sorted((s for s in spans if s is not rpc), key=lambda s: s["mono_s"])
+        lo, hi = rpc["mono_s"], rpc["mono_s"] + rpc["duration_s"]
+        end = lo
+        for s in children:
+            assert s["mono_s"] >= end  # siblings follow one another
+            end = s["mono_s"] + s["duration_s"]
+            assert end <= hi
+            assert s["status"] == "ok"
+
+    def test_bad_sampling_is_refused(self, scorer):
+        _, client = scorer
+        with pytest.raises(FlightInvalidArgument):
+            scrape_trace(client, sample="some")
+        assert scorer[0].telemetry.sample == "caller"
+
+    def test_span_queue_stage_is_the_wait_and_inbox_dwell_is_not(self):
+        """The event loop's ``queue_wait`` histogram times every frame's
+        inbox dwell; a DoExchange's end of stream waits there behind its own
+        handler.  The span's ``queue`` stage is the opening frame's alone."""
+        from repro.serving import ScoringService
+
+        def slow(batch):
+            time.sleep(0.04)
+            return batch
+
+        srv = ScoringService(slow).serve_tcp()
+        try:
+            c = FlightClient(f"tcp://127.0.0.1:{srv.port}")
+            scrape_trace(c, sample="all", clear=True)
+            before = srv._listener.histograms()["queue_wait"].snapshot()
+            for _ in range(10):
+                score_once(c, [[1, 2]])
+            after = srv._listener.histograms()["queue_wait"].snapshot()
+            spans, _ = scrape_trace(c, sample="caller", clear=True)
+        finally:
+            srv.shutdown()
+        queue = [s["stages"]["queue"] for s in spans if s["name"] == "DoExchange:path:score"]
+        assert len(queue) == 10
+        dwell = (after["sum"] - before["sum"]) / (after["count"] - before["count"])
+        assert sum(queue) / len(queue) < 0.005
+        assert dwell >= 0.005
+
+    def test_ring_counts_drops_and_the_scrape_reports_them(self):
+        ring = SpanRecorder(maxlen=4)
+        for i in range(6):
+            ring.record(Span("t", f"s{i}", None, "x"))
+        assert (ring.recorded, ring.dropped) == (6, 2)
+        assert [s.span_id for s in ring.snapshot()] == ["s2", "s3", "s4", "s5"]
+
+        srv = InMemoryFlightServer()
+        srv.telemetry.spans = SpanRecorder(maxlen=2)
+        srv.serve_tcp()
+        try:
+            c = FlightClient(f"tcp://127.0.0.1:{srv.port}")
+            with Tracer().trace("list"):
+                for _ in range(3):
+                    c.list_flights()
+            spans, totals = scrape_trace(c, clear=True)
+        finally:
+            srv.shutdown()
+        assert len(spans) == 2
+        assert totals == {"sample": "caller", "recorded": 3, "dropped": 1, "returned": 2}
+
+    def test_mono_s_column_round_trips(self):
+        span = Span("t", "s", None, "x", start_s=1.7e9, mono_s=12.5)
+        [row] = batch_to_spans(decode_telemetry_batch(
+            encode_telemetry_batch(spans_to_batch([span]))))
+        assert row["mono_s"] == 12.5 and row["start_s"] == 1.7e9
+
+    def test_untraced_span_is_one_shared_null_context(self):
+        tel = ServerTelemetry("full")
+        assert tel.span("a") is tel.span("b")
+        with tel.span("a") as sp:
+            assert sp is None
+        assert len(tel.spans) == 0
