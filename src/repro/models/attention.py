@@ -91,10 +91,14 @@ def init_attention(pb: ParamBuilder, d_model: int, layout: HeadLayout,
     lead = (stack,) if stack is not None else ()
     lax_ = ("layers",) if stack is not None else ()
     hd, Ke, Gq, K = layout.head_dim, layout.eff_kv, layout.q_per_kv, layout.n_kv_heads
-    pb.param("wq", lead + (d_model, Ke, Gq, hd), lax_ + ("embed", "kv_heads", "q_per_kv", "head_dim"))
-    pb.param("wk", lead + (d_model, K, hd), lax_ + ("embed", layout.kv_logical, "head_dim"))
-    pb.param("wv", lead + (d_model, K, hd), lax_ + ("embed", layout.kv_logical, "head_dim"))
-    pb.param("wo", lead + (Ke, Gq, hd, d_model), lax_ + ("kv_heads", "q_per_kv", "head_dim", "embed"))
+    pb.param("wq", lead + (d_model, Ke, Gq, hd),
+             lax_ + ("embed", "kv_heads", "q_per_kv", "head_dim"), matmul=True)
+    pb.param("wk", lead + (d_model, K, hd), lax_ + ("embed", layout.kv_logical, "head_dim"),
+             matmul=True)
+    pb.param("wv", lead + (d_model, K, hd), lax_ + ("embed", layout.kv_logical, "head_dim"),
+             matmul=True)
+    pb.param("wo", lead + (Ke, Gq, hd, d_model),
+             lax_ + ("kv_heads", "q_per_kv", "head_dim", "embed"), matmul=True)
     if qk_norm:
         pb.param("q_norm", lead + (hd,), lax_ + ("head_dim",), init="ones")
         pb.param("k_norm", lead + (hd,), lax_ + ("head_dim",), init="ones")

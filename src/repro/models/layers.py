@@ -30,6 +30,10 @@ class ParamBuilder:
 
     ``abstract=True`` builds ShapeDtypeStructs instead of arrays — used by the
     dry-run to get the full param tree of 100B+ models with zero allocation.
+    ``matmul`` holds the path of every leaf declared ``matmul=True``: one the
+    forward pass reads only as a bf16 matmul operand (``dot``/``einsum``/
+    ``embed``), so it may be held in bf16 without changing any matmul's
+    operands.  All scopes of one builder share the set.
     """
 
     def __init__(self, key: jax.Array, dtype=jnp.float32, abstract: bool = False):
@@ -38,6 +42,8 @@ class ParamBuilder:
         self.abstract = abstract
         self.params: dict[str, Any] = {}
         self.axes: dict[str, Any] = {}
+        self.path: tuple[str, ...] = ()
+        self.matmul: set[tuple[str, ...]] = set()
 
     def _next_key(self) -> jax.Array:
         self._key, k = jax.random.split(self._key)
@@ -51,9 +57,12 @@ class ParamBuilder:
         init: str | float = "normal",
         scale: float | None = None,
         dtype=None,
+        matmul: bool = False,
     ):
         assert len(shape) == len(logical), f"{name}: {shape} vs {logical}"
         dtype = dtype or self.dtype
+        if matmul:
+            self.matmul.add(self.path + (name,))
         if self.abstract:
             self.params[name] = jax.ShapeDtypeStruct(shape, dtype)
             self.axes[name] = logical
@@ -76,6 +85,7 @@ class ParamBuilder:
 
     def scope(self, name: str) -> "ParamBuilder":
         sub = ParamBuilder(self._next_key(), self.dtype, self.abstract)
+        sub.path, sub.matmul = self.path + (name,), self.matmul
         self.params[name] = sub.params
         self.axes[name] = sub.axes
         return sub
@@ -174,11 +184,11 @@ def init_mlp(pb: ParamBuilder, d_model: int, d_ff: int, stack: int | None = None
     lead = (stack,) if stack is not None else ()
     lax = ("layers",) if stack is not None else ()
     if activation == "swiglu":
-        pb.param("w_gate", lead + (d_model, d_ff), lax + ("embed", "ff"))
-        pb.param("w_up", lead + (d_model, d_ff), lax + ("embed", "ff"))
+        pb.param("w_gate", lead + (d_model, d_ff), lax + ("embed", "ff"), matmul=True)
+        pb.param("w_up", lead + (d_model, d_ff), lax + ("embed", "ff"), matmul=True)
     else:
-        pb.param("w_up", lead + (d_model, d_ff), lax + ("embed", "ff"))
-    pb.param("w_down", lead + (d_ff, d_model), lax + ("ff", "embed"))
+        pb.param("w_up", lead + (d_model, d_ff), lax + ("embed", "ff"), matmul=True)
+    pb.param("w_down", lead + (d_ff, d_model), lax + ("ff", "embed"), matmul=True)
 
 
 def mlp(params: dict, x: jax.Array, ctx, activation: str = "swiglu") -> jax.Array:
@@ -198,7 +208,7 @@ def mlp(params: dict, x: jax.Array, ctx, activation: str = "swiglu") -> jax.Arra
 
 
 def init_embedding(pb: ParamBuilder, vocab: int, d_model: int) -> None:
-    pb.param("embedding", (vocab, d_model), ("vocab", "embed"), scale=0.02)
+    pb.param("embedding", (vocab, d_model), ("vocab", "embed"), scale=0.02, matmul=True)
 
 
 def embed(params: dict, tokens: jax.Array, ctx) -> jax.Array:

@@ -189,6 +189,15 @@ class LM:
 
     # ---------------------------------------------------------------- init --
     def init(self, key: jax.Array, abstract: bool = False):
+        pb = self._build(key, abstract)
+        return pb.params, pb.axes
+
+    def matmul_leaves(self) -> frozenset[tuple[str, ...]]:
+        """Paths of the param leaves the forward pass reads only as bf16
+        matmul operands (declared ``matmul=True`` where they are built)."""
+        return frozenset(self._build(jax.random.key(0), abstract=True).matmul)
+
+    def _build(self, key: jax.Array, abstract: bool) -> L.ParamBuilder:
         cfg = self.cfg
         pb = L.ParamBuilder(key, cfg.param_dtype, abstract=abstract)
         L.init_embedding(pb, cfg.vocab, cfg.d_model)
@@ -221,10 +230,12 @@ class LM:
         fb = pb.scope("final")
         self._init_norm(fb, "norm_out", None)
         if cfg.frontend == "vision":
-            pb.param("patch_proj", (cfg.frontend_dim, cfg.d_model), ("patch", "embed"))
+            pb.param("patch_proj", (cfg.frontend_dim, cfg.d_model), ("patch", "embed"),
+                     matmul=True)
         elif cfg.frontend == "audio":
-            pb.param("frame_proj", (cfg.frontend_dim, cfg.d_model), ("patch", "embed"))
-        return pb.params, pb.axes
+            pb.param("frame_proj", (cfg.frontend_dim, cfg.d_model), ("patch", "embed"),
+                     matmul=True)
+        return pb
 
     def _init_norm(self, pb: L.ParamBuilder, name: str, stack: int | None):
         lead = (stack,) if stack is not None else ()

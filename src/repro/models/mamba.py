@@ -41,7 +41,7 @@ def init_mamba(pb: ParamBuilder, cfg: MambaConfig, stack: int | None = None) -> 
     lead = (stack,) if stack is not None else ()
     lax_ = ("layers",) if stack is not None else ()
     D, Din, N, R = cfg.d_model, cfg.d_inner, cfg.d_state, cfg.dt_rank
-    pb.param("w_in", lead + (D, 2 * Din), lax_ + ("embed", "inner"))
+    pb.param("w_in", lead + (D, 2 * Din), lax_ + ("embed", "inner"), matmul=True)
     pb.param("conv_w", lead + (cfg.d_conv, Din), lax_ + ("conv", "inner"), scale=0.5)
     pb.param("conv_b", lead + (Din,), lax_ + ("inner",), init="zeros")
     pb.param("w_x", lead + (Din, R + 2 * N), lax_ + ("inner", "dt"))
@@ -49,7 +49,7 @@ def init_mamba(pb: ParamBuilder, cfg: MambaConfig, stack: int | None = None) -> 
     pb.param("b_dt", lead + (Din,), lax_ + ("inner",), init=-4.6)  # softplus≈0.01
     pb.param("A_log", lead + (Din, N), lax_ + ("inner", "state"), init=0.5)
     pb.param("D_skip", lead + (Din,), lax_ + ("inner",), init="ones")
-    pb.param("w_out", lead + (Din, D), lax_ + ("inner", "embed"))
+    pb.param("w_out", lead + (Din, D), lax_ + ("inner", "embed"), matmul=True)
 
 
 def _causal_conv(x, w, b, state=None):

@@ -49,14 +49,14 @@ def init_moe(pb: ParamBuilder, cfg: MoEConfig, stack: int | None = None) -> None
     # top_k across the model axis into EVERY layer (measured 0.4 s/step of
     # collectives on moonshot train_4k — §Perf C-cell)
     pb.param("w_router", lead + (D, E), lax_ + ("embed_nosplit", "experts_rep"), scale=0.02)
-    pb.param("w_gate", lead + (E, D, F), lax_ + ("experts", "embed", "ff_nosplit"))
-    pb.param("w_up", lead + (E, D, F), lax_ + ("experts", "embed", "ff_nosplit"))
-    pb.param("w_down", lead + (E, F, D), lax_ + ("experts", "ff_nosplit", "embed"))
+    pb.param("w_gate", lead + (E, D, F), lax_ + ("experts", "embed", "ff_nosplit"), matmul=True)
+    pb.param("w_up", lead + (E, D, F), lax_ + ("experts", "embed", "ff_nosplit"), matmul=True)
+    pb.param("w_down", lead + (E, F, D), lax_ + ("experts", "ff_nosplit", "embed"), matmul=True)
     if cfg.n_shared_experts:
         Fs = F * cfg.n_shared_experts
-        pb.param("ws_gate", lead + (D, Fs), lax_ + ("embed", "ff"))
-        pb.param("ws_up", lead + (D, Fs), lax_ + ("embed", "ff"))
-        pb.param("ws_down", lead + (Fs, D), lax_ + ("ff", "embed"))
+        pb.param("ws_gate", lead + (D, Fs), lax_ + ("embed", "ff"), matmul=True)
+        pb.param("ws_up", lead + (D, Fs), lax_ + ("embed", "ff"), matmul=True)
+        pb.param("ws_down", lead + (Fs, D), lax_ + ("ff", "embed"), matmul=True)
 
 
 def _group_by_expert(expert_idx: jax.Array, weights: jax.Array, n_local: int, capacity: int):

@@ -57,15 +57,15 @@ def init_mlstm(pb: ParamBuilder, cfg: XLSTMConfig, stack: int | None = None) -> 
     lead = (stack,) if stack is not None else ()
     lax_ = ("layers",) if stack is not None else ()
     D, Di, H = cfg.d_model, cfg.d_inner_m, cfg.n_heads
-    pb.param("w_up", lead + (D, 2 * Di), lax_ + ("embed", "inner"))
-    pb.param("w_q", lead + (Di, Di), lax_ + ("inner", "inner_nosplit"))
-    pb.param("w_k", lead + (Di, Di), lax_ + ("inner", "inner_nosplit"))
-    pb.param("w_v", lead + (Di, Di), lax_ + ("inner", "inner_nosplit"))
+    pb.param("w_up", lead + (D, 2 * Di), lax_ + ("embed", "inner"), matmul=True)
+    pb.param("w_q", lead + (Di, Di), lax_ + ("inner", "inner_nosplit"), matmul=True)
+    pb.param("w_k", lead + (Di, Di), lax_ + ("inner", "inner_nosplit"), matmul=True)
+    pb.param("w_v", lead + (Di, Di), lax_ + ("inner", "inner_nosplit"), matmul=True)
     pb.param("w_if", lead + (Di, 2 * H), lax_ + ("inner", "heads_nosplit"), scale=0.02)
     pb.param("b_if", lead + (2 * H,), lax_ + ("heads_nosplit",), init="zeros")
     pb.param("ln_w", lead + (Di,), lax_ + ("inner",), init="ones")
     pb.param("ln_b", lead + (Di,), lax_ + ("inner",), init="zeros")
-    pb.param("w_down", lead + (Di, D), lax_ + ("inner", "embed"))
+    pb.param("w_down", lead + (Di, D), lax_ + ("inner", "embed"), matmul=True)
 
 
 def _mlstm_chunk(carry, inp, H, dh):
@@ -194,7 +194,7 @@ def init_slstm(pb: ParamBuilder, cfg: XLSTMConfig, stack: int | None = None) -> 
     lax_ = ("layers",) if stack is not None else ()
     D, H = cfg.d_model, cfg.n_heads
     dh = D // H
-    pb.param("w_gates", lead + (D, 4 * D), lax_ + ("embed", "inner"))
+    pb.param("w_gates", lead + (D, 4 * D), lax_ + ("embed", "inner"), matmul=True)
     # fan-in init (std 1/sqrt(dh)): a fixed 0.4 made gradients through the
     # time scan overflow to NaN within 1024 steps at 4 heads of 256
     pb.param("r_gates", lead + (H, dh, 4 * dh), lax_ + ("heads_nosplit", "head_dim", "head_dim"))
@@ -202,8 +202,8 @@ def init_slstm(pb: ParamBuilder, cfg: XLSTMConfig, stack: int | None = None) -> 
     pb.param("ln_w", lead + (D,), lax_ + ("embed_nosplit",), init="ones")
     pb.param("ln_b", lead + (D,), lax_ + ("embed_nosplit",), init="zeros")
     dff = cfg.d_ff_s
-    pb.param("w_ff1", lead + (D, 2 * dff), lax_ + ("embed", "ff"))
-    pb.param("w_ff2", lead + (dff, D), lax_ + ("ff", "embed"))
+    pb.param("w_ff1", lead + (D, 2 * dff), lax_ + ("embed", "ff"), matmul=True)
+    pb.param("w_ff2", lead + (dff, D), lax_ + ("ff", "embed"), matmul=True)
 
 
 def _slstm_scan(pre, st0, r_gates, H: int):

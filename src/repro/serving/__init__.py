@@ -1,1 +1,8 @@
-from .service import Batcher, BatcherConfig, LMScoringService, ScoringService, score_tokens  # noqa: F401
+from .service import (  # noqa: F401
+    Batcher,
+    BatcherConfig,
+    LMScoringService,
+    ScoringService,
+    compute_params,
+    score_tokens,
+)

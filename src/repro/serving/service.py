@@ -108,21 +108,44 @@ def score_tokens(model, params, tokens, lengths):
     return jnp.argmax(lgts, axis=-1).astype(jnp.int32), jnp.max(lp, axis=-1)
 
 
+def compute_params(model, params):
+    """``params`` with each leaf ``model`` declares a matmul operand in bf16.
+
+    The forward pass reads those leaves only through a cast to bf16, so
+    casting them once here hands its matmuls the same operands, bit for bit,
+    that a program given float32 weights would cast again on every call.
+    Other leaves (norms, gates, recurrent weights) keep their dtype.  Works
+    on a tree of arrays and, under ``jax.eval_shape``, of shapes."""
+    marked = model.matmul_leaves()
+
+    def cast(path, x):
+        return x.astype(jnp.bfloat16) if tuple(k.key for k in path) in marked else x
+
+    return jax.tree_util.tree_map_with_path(cast, params)
+
+
 class LMScoringService(ScoringService):
     """Scores ``tokens`` list-columns with an LM prefill (greedy next token).
 
-    Every batch is padded to ``(rows, max_seq)``: ``tokens_real`` counts the
-    prompt tokens scored, ``tokens_padded`` the slots they were padded into
-    (both exported beside ``requests``), so their ratio is the padding's
-    fill.  A traced request leaves four child spans of its RPC span:
-    ``serve.decode`` (rows to the padded array), ``serve.dispatch`` (the
-    jitted call returning; the device runs on asynchronously),
-    ``serve.sync`` (waiting for the outputs, behind any other worker's
-    programs too) and ``serve.reply`` (the reply batch)."""
+    The service holds ``compute_params(model, params)``: its matmul weights
+    arrive at ``score_tokens`` in bf16.  ``weight_bytes`` (every leaf held)
+    and ``weight_bytes_compute`` (the bf16 matmul leaves among them) are set
+    at load, not counted per call.  Every batch is padded to
+    ``(rows, max_seq)``: ``tokens_real`` counts the prompt tokens scored,
+    ``tokens_padded`` the slots they were padded into, so their ratio is the
+    padding's fill.  All four are exported beside ``requests``.  A traced
+    request leaves four child spans of its RPC span: ``serve.decode`` (rows
+    to the padded array), ``serve.dispatch`` (the jitted call returning; the
+    device runs on asynchronously), ``serve.sync`` (waiting for the outputs,
+    behind any other worker's programs too) and ``serve.reply`` (the reply
+    batch)."""
 
     def __init__(self, model, params, max_seq: int = 512, **kw):
         self.model = model
-        self.params = params
+        self.params = compute_params(model, params)
+        leaves = jax.tree.leaves(self.params)
+        self.weight_bytes = sum(x.nbytes for x in leaves)
+        self.weight_bytes_compute = sum(x.nbytes for x in leaves if x.dtype == jnp.bfloat16)
         self.max_seq = max_seq
         self.tokens_real = 0
         self.tokens_padded = 0
@@ -130,7 +153,8 @@ class LMScoringService(ScoringService):
 
     def serve_counters(self) -> dict:
         return {**super().serve_counters(), "tokens_real": self.tokens_real,
-                "tokens_padded": self.tokens_padded}
+                "tokens_padded": self.tokens_padded, "weight_bytes": self.weight_bytes,
+                "weight_bytes_compute": self.weight_bytes_compute}
 
     def _score_batch(self, batch: RecordBatch) -> RecordBatch:
         span = self.telemetry.span

@@ -2,16 +2,32 @@
 import threading
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs import get_smoke_config
+from repro.configs import ARCH_IDS, get_smoke_config
 from repro.core import RecordBatch
 from repro.core.flight import FlightClient, FlightDescriptor, InMemoryFlightServer
 from repro.data import FlightDataLoader, LoaderState, pack_documents, synthesize_corpus
 from repro.distributed.sharding import single_device_ctx
 from repro.models.lm import LM
-from repro.serving import Batcher, BatcherConfig, LMScoringService
+from repro.serving import Batcher, BatcherConfig, LMScoringService, score_tokens
+
+# every family the service can feed: token prompts, no vision or audio frontend
+SERVABLE = [a for a in ARCH_IDS if get_smoke_config(a).frontend is None]
+INTERNLM2_MATMUL = {"embedding", "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down"}
+
+
+def off_bf16_grid(params, key):
+    """Moves each leaf ``init`` fills with one constant (norm weights and
+    biases, ``D_skip``, ``A_log``, ``b_dt``...) to c + 1e-3·normal, values bf16
+    cannot hold: such a leaf held in bf16 then changes the answers."""
+    leaves, tree = jax.tree.flatten(params)
+    keys = jax.random.split(key, len(leaves))
+    return jax.tree.unflatten(tree, [
+        x + 1e-3 * jax.random.normal(k, x.shape, x.dtype) if bool(jnp.all(x == x.ravel()[0]))
+        else x for x, k in zip(leaves, keys)])
 
 
 @pytest.fixture(scope="module")
@@ -106,6 +122,70 @@ class TestScoring:
             lp = jax.nn.log_softmax(np.asarray(lg[0], np.float32))
             assert out.column("next_token").to_pylist()[i] == int(np.argmax(lp))
             assert abs(out.column("logprob").to_pylist()[i] - float(np.max(lp))) <= 1e-5
+
+    @pytest.mark.parametrize("arch", SERVABLE)
+    def test_held_weights_score_bit_for_bit_as_float32(self, arch):
+        """Matmul weights held in bf16 give exactly the answers of the float32
+        tree the program would cast on every call."""
+        cfg = get_smoke_config(arch)
+        model = LM(cfg, single_device_ctx())
+        params, _ = model.init(jax.random.key(0))
+        params = off_bf16_grid(params, jax.random.key(1))
+        svc = LMScoringService(model, params, max_seq=32)
+        rng = np.random.default_rng(0)
+        toks = rng.integers(1, cfg.vocab, (3, 32)).astype(np.int32)
+        lens = np.asarray([32, 5, 17], np.int32)
+        held = score_tokens(model, svc.params, toks, lens)
+        f32 = score_tokens(model, params, toks, lens)
+        for a, b in zip(held, f32):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert any(x.dtype == jnp.bfloat16 for x in jax.tree.leaves(svc.params))
+
+    def test_internlm2_holds_exactly_its_matmul_weights_in_bf16(self):
+        model = LM(get_smoke_config("internlm2_1_8b"), single_device_ctx())
+        params, _ = model.init(jax.random.key(0))
+        held = {"/".join(k.key for k in path): x for path, x in
+                jax.tree_util.tree_flatten_with_path(
+                    LMScoringService(model, params, max_seq=32).params)[0]}
+        bf16 = [k for k, x in held.items() if x.dtype == jnp.bfloat16]
+        assert sorted(k.rsplit("/", 1)[-1] for k in bf16) == sorted(INTERNLM2_MATMUL)
+        norms = [x for k, x in held.items() if "norm" in k]
+        assert norms and all(x.dtype == jnp.float32 for x in norms)
+
+    def test_weight_bytes_are_set_at_load(self):
+        """``weight_bytes`` and ``weight_bytes_compute`` count what the service
+        holds, reach ``server-metrics``, and no call moves them."""
+        from repro.core.flight import Action, batch_to_rows, decode_telemetry_batch
+
+        model = LM(get_smoke_config("internlm2_1_8b"), single_device_ctx())
+        params, _ = model.init(jax.random.key(0))
+        svc = LMScoringService(model, params, max_seq=32).serve_tcp()
+        try:
+            c = FlightClient(f"tcp://127.0.0.1:{svc.port}")
+
+            def scrape() -> dict:
+                rows = batch_to_rows(decode_telemetry_batch(
+                    c.do_action(Action("server-metrics", b""))[0].body))
+                return {r["name"]: r["count"] for r in rows if r["scope"] == "serve"}
+
+            before = scrape()
+            req = RecordBatch.from_pydict({"tokens": [[1, 2, 3]]})
+            ex = c.do_exchange_stream(FlightDescriptor.for_path("score"), req.schema)
+            ex.feed([req])
+            assert len(list(ex)) == 1
+            ex.close()
+            after = scrape()
+        finally:
+            svc.shutdown()
+        held = jax.tree.leaves(svc.params)
+        total = sum(x.size * x.dtype.itemsize for x in held)
+        compute = sum(x.size * 2 for x in held if x.dtype == jnp.bfloat16)
+        counters = svc.serve_counters()
+        assert (counters["weight_bytes"], counters["weight_bytes_compute"]) == (total, compute)
+        assert total < sum(x.size * 4 for x in held)
+        assert compute / total >= 0.99
+        for k in ("weight_bytes", "weight_bytes_compute"):
+            assert before[k] == after[k] == counters[k]
 
     def test_request_counter_survives_concurrent_workers(self):
         """Handlers of many connections bump the serve counters at once."""

@@ -7,6 +7,7 @@ widths and batch shapes ``chip_smoke.py`` runs.  The topology is described
 inside a fixture, so only the worker that runs this file loads the TPU library.
 """
 import os
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -18,7 +19,7 @@ from chip_smoke import SERVE_MAX_SEQ, SERVE_ROWS, TRAIN_BATCH, TRAIN_SEQ
 from repro.configs import get_config
 from repro.distributed.sharding import ShardingCtx, tree_shardings
 from repro.models.lm import LM
-from repro.serving import score_tokens
+from repro.serving import compute_params, score_tokens
 from repro.train.loop import Trainer, TrainerConfig
 from repro.train.optimizer import opt_state_axes_with_params
 
@@ -70,6 +71,29 @@ def test_score_tokens_compiles_internlm2(model_on_chip, one_chip):
     param_bytes = sum(p.size * p.dtype.itemsize for p in jax.tree.leaves(params))
     # the weights are arguments of the program, not constants baked into it
     assert compiled.memory_analysis().argument_size_in_bytes >= param_bytes
+
+
+def test_score_tokens_reads_matmul_weights_without_a_cast(model_on_chip, one_chip):
+    """Given the service's tree, the program takes its matmul weights in bf16:
+    no float32 value of a weight's shape, whole or one layer's slice, is left
+    in it to cast, and its arguments are about half the float32 tree."""
+    model = model_on_chip("internlm2_1_8b")
+    params, _ = model.init(jax.random.key(0), abstract=True)
+    held = jax.eval_shape(partial(compute_params, model), params)
+    tokens = jax.ShapeDtypeStruct((SERVE_ROWS, SERVE_MAX_SEQ), jnp.int32)
+    lengths = jax.ShapeDtypeStruct((SERVE_ROWS,), jnp.int32)
+    compiled = score_tokens.lower(model, *on(one_chip, (held, tokens, lengths))).compile()
+    hlo = compiled.as_text()
+    stacked = {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down"}  # (layers, ...)
+    shapes = [params["embedding"].shape]
+    for path, w in jax.tree_util.tree_flatten_with_path(params)[0]:
+        if path[-1].key in stacked:
+            shapes += [w.shape, w.shape[1:]]
+    assert len(shapes) == 1 + 2 * len(stacked)
+    for shape in shapes:
+        assert f"f32[{','.join(map(str, shape))}]" not in hlo
+    f32_bytes = sum(p.size * p.dtype.itemsize for p in jax.tree.leaves(params))
+    assert compiled.memory_analysis().argument_size_in_bytes <= 0.55 * f32_bytes
 
 
 def test_train_step_compiles_xlstm(model_on_chip, one_chip, tmp_path):
